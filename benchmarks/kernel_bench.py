@@ -78,7 +78,7 @@ def run_kernel_fusion_bench(n: int = 1200, k_fused: int = 8, seed: int = 0):
 
     g = random_geometric(n, avg_degree=3.0, seed=seed)
     chain = PallasBackend(g, impl="ref")
-    fused = PallasBackend(g, impl="ref", fuse=k_fused)
+    fused = PallasBackend(g, impl="interpret", fuse=k_fused)
     st = chain.init_state()
     st = st._replace(d=st.d.at[0].set(0), c=st.c.at[0].set(0),
                      pathw=st.pathw.at[0].set(0))

@@ -11,6 +11,8 @@ import sys
 import time
 import traceback
 
+from repro.common import enable_compile_cache
+
 from benchmarks import (
     cluster2_ablation,
     delta_init,
@@ -37,6 +39,7 @@ def main() -> int:
     ap.add_argument("--scale", type=float, default=1.0)
     ap.add_argument("--only", default="")
     args = ap.parse_args()
+    enable_compile_cache()
 
     failures = []
     for name, fn in TABLES.items():

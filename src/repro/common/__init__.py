@@ -1,7 +1,8 @@
-"""Shared low-level utilities: dtypes, padding, timing, logging, jax shims."""
-from repro.common.compat import shard_map
+"""Shared low-level utilities: dtypes, padding, timing, logging."""
 from repro.common.util import (
     bench_engine_path,
+    checkout_root,
+    enable_compile_cache,
     ceil_div,
     pad_to_multiple,
     pad_axis_to,
@@ -13,8 +14,9 @@ from repro.common.util import (
 )
 
 __all__ = [
-    "shard_map",
     "bench_engine_path",
+    "checkout_root",
+    "enable_compile_cache",
     "ceil_div",
     "pad_to_multiple",
     "pad_axis_to",

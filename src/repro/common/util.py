@@ -59,14 +59,40 @@ def tree_num_params(tree: Any) -> int:
     return sum(int(np.prod(getattr(l, "shape", ()), dtype=np.int64)) for l in leaves)
 
 
+def checkout_root() -> str:
+    """Root of the checkout this package runs from (``src/..``)."""
+    import os
+
+    return os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__)))))
+
+
 def bench_engine_path() -> str:
     """Repo-root ``BENCH_engine.json`` — the ONE location the engine bench
     writes and the serve sync-budget check reads (both must agree)."""
     import os
 
-    root = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__)))))
-    return os.path.join(root, "BENCH_engine.json")
+    return os.path.join(checkout_root(), "BENCH_engine.json")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; entry points call this
+    once, before their first compile (never at import). Returns the cache
+    directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing else is set here. Otherwise the cache goes to ``.jax_cache`` in
+    the checkout: a fixed path, because the directory is part of the cache
+    key and a path that moves never hits.
+    """
+    import os
+
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(checkout_root(), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 class Timer:

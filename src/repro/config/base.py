@@ -212,10 +212,11 @@ class GraphEngineConfig(ArchConfig):
     comm: str = "halo"               # sharded backend collective: halo (static
                                      # boundary-row exchange, default) | allgather
                                      # (full-plane baseline); byte-identical results
-    relax_impl: str = "auto"         # pallas backend kernel impl: auto | ref | pallas
+    relax_impl: str = "auto"         # pallas backend kernel impl:
+                                     # auto | ref | pallas | interpret
     autotune: str = "off"            # off | auto | record (core/autotune.py)
-    fuse_supersteps: int = 0         # pallas megakernel fusion depth
-                                     # (0 = unfused unless the autotuner engages)
+    fuse_supersteps: int = 0         # pallas megakernel fusion depth (0 =
+                                     # unfused; > 0 needs relax_impl=interpret)
     node_tile: int = 0               # pallas tiling overrides; 0 = kernel
     edge_block: int = 0              # defaults (or autotuned under autotune)
     mode: str = "stages"             # stages | oneshot | auto (core/engine.py

@@ -12,7 +12,7 @@ ONE cheap device pass over the edges:
     and fetched in a single packed int32 vector (one host sync);
   * ``derive_tuning`` turns the statistics into a ``TuningRecord``;
   * kernel tiling candidates are scored with the ``runtime/roofline.py``
-    machine constants (HBM stream time vs VPU match-matrix time), and
+    peaks of the chip (HBM stream time vs VPU match-matrix time), and
     ``validate_tuning`` re-checks the chosen tiling against the model and
     the kernel preconditions (``kernels/edge_relax/kernel.validate_tiling``);
   * records are cached in-process keyed by a graph signature; ``record``
@@ -42,8 +42,7 @@ from repro.common import get_logger, next_multiple
 from repro.graph.segment_ops import segment_aggregate
 from repro.graph.structures import EdgeList
 from repro.kernels.edge_relax.kernel import validate_tiling
-from repro.kernels.edge_relax.megakernel import DEFAULT_K_FUSED, fits_vmem
-from repro.runtime.roofline import HBM_BW, PEAK_FLOPS
+from repro.runtime.roofline import V5E, ChipPeaks, peaks_for
 
 log = get_logger("repro.autotune")
 
@@ -173,7 +172,7 @@ def graph_signature(stats: GraphStats) -> str:
 
 
 def _tiling_time(n_nodes: int, n_edges: int, node_tile: int,
-                 edge_block: int) -> Tuple[float, int]:
+                 edge_block: int, peaks: ChipPeaks) -> Tuple[float, int]:
     """Roofline estimate (seconds, padded edge slots) for one relax pass.
 
     HBM term: the blocked (src, dst, w, mask) int32 arrays stream once.
@@ -188,18 +187,20 @@ def _tiling_time(n_nodes: int, n_edges: int, node_tile: int,
     per_tile = n_edges / n_tiles
     blocks_per_tile = max(math.ceil((per_tile + edge_block / 2) / edge_block), 1)
     padded = n_tiles * blocks_per_tile * edge_block
-    t_hbm = (padded * 4 * 4) / HBM_BW
-    t_compute = (padded * node_tile * 3) / (PEAK_FLOPS / _VPU_DISCOUNT)
+    t_hbm = (padded * 4 * 4) / peaks.hbm_bw
+    t_compute = (padded * node_tile * 3) / (peaks.flops / _VPU_DISCOUNT)
     return max(t_hbm, t_compute), padded
 
 
-def _best_tiling(stats: GraphStats) -> Tuple[int, int, float, int]:
+def _best_tiling(stats: GraphStats,
+                 peaks: ChipPeaks) -> Tuple[int, int, float, int]:
     best = None
     for nt in NODE_TILE_CANDIDATES:
         for eb in EDGE_BLOCK_CANDIDATES:
             if nt * eb * 4 * 4 > _MAX_MATRIX_BYTES:
                 continue
-            t, padded = _tiling_time(stats.n_nodes, stats.n_edges, nt, eb)
+            t, padded = _tiling_time(stats.n_nodes, stats.n_edges, nt, eb,
+                                     peaks)
             if best is None or t < best[2]:
                 best = (nt, eb, t, padded)
     assert best is not None
@@ -216,11 +217,26 @@ def _median_weight_bucket(stats: GraphStats) -> int:
     return 0
 
 
-def derive_tuning(stats: GraphStats, *, backend: str = "single",
+def kernel_peaks() -> ChipPeaks:
+    """Peaks the kernel tiling is priced against: the attached TPU's table
+    entry (a TPU kind missing from the table raises). Off a TPU the kernel
+    never runs compiled and the tiling only shapes the blocked layout, so it
+    is priced for the v5e the kernel is written for."""
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        return peaks_for(dev.device_kind)
+    return V5E
+
+
+def derive_tuning(stats: GraphStats, *, peaks: ChipPeaks,
                   tau_fraction: float = 1e-3) -> TuningRecord:
     """Map graph statistics to pipeline knobs. Every choice here is a
     PERFORMANCE decision — the pipeline is correct for any legal value —
-    so the formulas are deliberately simple and documented in place."""
+    so the formulas are deliberately simple and documented in place.
+    ``peaks`` prices the kernel tiling (see ``kernel_peaks``).
+
+    ``fuse`` is always 0: the TPU compiler refuses the fused megakernel's
+    in-kernel 1-D gather (``kernels/edge_relax/megakernel.py``)."""
     n = max(stats.n_nodes, 1)
     logn = max(math.log(max(n, 2)), 1.0)
 
@@ -249,12 +265,7 @@ def derive_tuning(stats: GraphStats, *, backend: str = "single",
     b = _median_weight_bucket(stats)
     delta_init = max(1, min(2 ** (b + 1), 2**30 - 1))
 
-    node_tile, edge_block, pred_t, padded = _best_tiling(stats)
-    n_pad = next_multiple(n + 1, node_tile)
-    fuse = 0
-    if (backend == "pallas" and jax.default_backend() == "tpu"
-            and fits_vmem(n_pad, node_tile, edge_block)):
-        fuse = DEFAULT_K_FUSED
+    node_tile, edge_block, pred_t, padded = _best_tiling(stats, peaks)
 
     # engine mode for cfg.mode="auto" sessions: the stage loop halves the
     # uncovered set per stage until the 8*tau*log n threshold, so it needs
@@ -271,11 +282,12 @@ def derive_tuning(stats: GraphStats, *, backend: str = "single",
     return TuningRecord(
         signature=graph_signature(stats), tau=tau, tau_solve=tau_solve,
         levels=levels, delta_init=delta_init, node_tile=node_tile,
-        edge_block=edge_block, fuse=fuse, predicted_superstep_s=pred_t,
+        edge_block=edge_block, fuse=0, predicted_superstep_s=pred_t,
         padded_edges=padded, mode=mode)
 
 
-def validate_tuning(rec: TuningRecord, stats: GraphStats) -> None:
+def validate_tuning(rec: TuningRecord, stats: GraphStats,
+                    peaks: ChipPeaks) -> None:
     """Re-check a record against the kernel preconditions and the roofline
     model (guards hand-edited or stale cache entries)."""
     validate_tiling(rec.node_tile, rec.edge_block)
@@ -294,8 +306,8 @@ def validate_tuning(rec: TuningRecord, stats: GraphStats) -> None:
             f"mode must be 'stages' or 'oneshot' (a record stores the "
             f"RESOLVED mode, never 'auto'), got {rec.mode!r}")
     t, _ = _tiling_time(stats.n_nodes, stats.n_edges,
-                        rec.node_tile, rec.edge_block)
-    best_t = _best_tiling(stats)[2]
+                        rec.node_tile, rec.edge_block, peaks)
+    best_t = _best_tiling(stats, peaks)[2]
     if t > best_t * 1.05:
         raise AutotuneError(
             f"tiling ({rec.node_tile}, {rec.edge_block}) predicted "
@@ -360,8 +372,9 @@ def get_tuning(edges: EdgeList, *, backend: str = "single",
         TUNE_EVENTS["hits"] += 1
         return hit
     TUNE_EVENTS["misses"] += 1
-    rec = derive_tuning(stats, backend=backend)
-    validate_tuning(rec, stats)
+    peaks = kernel_peaks()
+    rec = derive_tuning(stats, peaks=peaks)
+    validate_tuning(rec, stats, peaks)
     _CACHE[key] = rec
     if record:
         save_cache(cache_path)

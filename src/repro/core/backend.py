@@ -9,7 +9,8 @@ plane-based state (``EngineState``, padded once per decomposition by
   * ``ShardedBackend`` — wraps ``DistributedEngine`` (allgather or halo
     shard_map supersteps on a device mesh);
   * ``PallasBackend`` — routes the local relax through the fused
-    ``kernels/edge_relax`` kernel (Pallas on TPU, jnp oracle elsewhere).
+    ``kernels/edge_relax`` kernel (compiled Pallas on TPU; the jnp oracle
+    or the interpreted kernel when asked for by ``impl``).
 
 All three share the same per-edge candidate rule
 (``kernels/edge_relax/ref.edge_relax_candidates``) and the same
@@ -118,7 +119,8 @@ def dispatch_grow(spec: GrowSpec, graph_args, state, delta, half_target,
                               edge_block, impl, variant)
     if kind == "sharded":
         (backend,) = spec[1:]
-        return backend.grow(state, delta, half_target, num_it, variant)
+        return _sharded_growth(backend.eng, state, graph_args, delta,
+                               half_target, num_it, variant)
     raise ValueError(f"unknown grow spec kind {kind!r}")
 
 
@@ -246,21 +248,24 @@ def _megakernel_growth(
     rule (``kernels/edge_relax/megakernel.py``)."""
     from repro.kernels.edge_relax.megakernel import megakernel_growth_loop
 
-    interpret = impl != "pallas" or jax.default_backend() != "tpu"
     return megakernel_growth_loop(
         state, bsrc, bdst, bw, bmask, block_tile,
         delta, half_target, num_it,
         n_tiles, node_tile, edge_block,
-        k_fused=fuse, interpret=interpret, variant=variant)
+        k_fused=fuse, interpret=impl == "interpret", variant=variant)
 
 
 class PallasBackend:
     """Blocked dst-sorted edge layout + fused one-pass relax kernel.
 
+    ``impl="auto"`` picks the compiled kernel on a TPU and the jnp
+    reference elsewhere; ``impl="pallas"`` off a TPU is an error.
+
     ``fuse > 0`` switches grow calls to the persistent megakernel: each
     while-loop body runs up to ``fuse`` supersteps in one pallas_call with
-    VMEM-resident planes and an on-chip frontier bitmap. Off TPU the
-    megakernel runs in interpret mode (parity/testing only — slow).
+    VMEM-resident planes and an on-chip frontier bitmap. The TPU compiler
+    refuses the megakernel's in-kernel 1-D gather, so it runs only with
+    ``impl="interpret"`` (parity/testing only — slow).
     """
 
     kind = "pallas"
@@ -270,15 +275,25 @@ class PallasBackend:
                  edge_block: Optional[int] = None,
                  fuse: int = 0):
         from repro.kernels.edge_relax.kernel import (
-            EDGE_BLOCK, NODE_TILE, validate_tiling)
+            EDGE_BLOCK, NODE_TILE, edge_slabs, validate_tiling)
         from repro.kernels.edge_relax.ops import block_edges_host
 
         self.node_tile = node_tile or NODE_TILE
         self.edge_block = edge_block or EDGE_BLOCK
         validate_tiling(self.node_tile, self.edge_block)
+        from repro.kernels.edge_relax.ops import resolve_impl
+
         if impl == "auto":
             impl = "pallas" if jax.default_backend() == "tpu" else "ref"
-        self.impl = impl
+        if fuse < 0:
+            raise ValueError(f"fuse must be >= 0, got {fuse}")
+        if fuse and impl != "interpret":
+            raise ValueError(
+                f"fuse={fuse} needs impl='interpret', got impl={impl!r}: the "
+                "TPU compiler refuses the fused megakernel's in-kernel 1-D "
+                "gather (ref[...].reshape(-1)[srcv]: 'Only 2D gather is "
+                "supported'); use fuse=0 for the chained kernel")
+        self.impl = resolve_impl(impl)
         blk = block_edges_host(edges.src, edges.dst, edges.weight,
                                edges.n_nodes, self.node_tile, self.edge_block)
         self.n_nodes = edges.n_nodes
@@ -286,8 +301,6 @@ class PallasBackend:
         self.n_tiles = blk["n_tiles"]
         if fuse:
             from repro.kernels.edge_relax.megakernel import fits_vmem
-            if fuse < 0:
-                raise ValueError(f"fuse must be >= 0, got {fuse}")
             if not fits_vmem(self.n_pad, self.node_tile, self.edge_block):
                 import warnings
                 warnings.warn(
@@ -296,10 +309,12 @@ class PallasBackend:
                     "pallas grow path", RuntimeWarning, stacklevel=2)
                 fuse = 0
         self.fuse = int(fuse)
-        self._bsrc = jnp.asarray(blk["src"])
-        self._bdst = jnp.asarray(blk["dst"])
-        self._bw = jnp.asarray(blk["w"])
-        self._bmask = jnp.asarray(blk["mask"])
+        # uploaded in the kernels' [n_blocks, 1, edge_block] slab layout
+        slabs = lambda x: jnp.asarray(edge_slabs(x, self.edge_block))
+        self._bsrc = slabs(blk["src"])
+        self._bdst = slabs(blk["dst"])
+        self._bw = slabs(blk["w"])
+        self._bmask = slabs(blk["mask"])
         self._btile = jnp.asarray(blk["block_tile"])
         self.transfers = 0
 
@@ -341,6 +356,22 @@ class PallasBackend:
 # ---------------------------------------------------------------------------
 
 
+def _sharded_growth(eng, state, gparts, delta, half_target, num_it,
+                    variant: str):
+    """One grow call on the engine's sharded planes. ``gparts`` (the edge
+    shards) arrive as operands: closed over by the engine's stage program,
+    they would be embedded in it as constants."""
+    rw0, rc, rp, frozen = relay_planes(state)
+    planes = (state.d, state.c, state.pathw, rw0, rc, rp, frozen)
+    planes, k, reached, changed = eng._growth(
+        planes, gparts, jnp.int32(delta),
+        jnp.int32(half_target), jnp.int32(num_it), variant=variant,
+    )
+    state = state._replace(d=planes[0], c=planes[1], pathw=planes[2])
+    return state, GrowthStats(steps=k, reached=reached,
+                              changed_last=changed)
+
+
 class ShardedBackend:
     """Wraps ``DistributedEngine``: shard_map supersteps on a device mesh.
 
@@ -370,7 +401,7 @@ class ShardedBackend:
         return GrowSpec("sharded", self)
 
     def graph_args(self):
-        return ()
+        return self.eng.gparts
 
     def quotient_args(self):
         # per-device [P, E_loc] shards, flattened with destinations mapped
@@ -383,15 +414,8 @@ class ShardedBackend:
                 g.weight.reshape(-1), g.edge_mask.reshape(-1).astype(bool))
 
     def grow(self, state, delta, half_target, num_it, variant):
-        rw0, rc, rp, frozen = relay_planes(state)
-        planes = (state.d, state.c, state.pathw, rw0, rc, rp, frozen)
-        planes, k, reached, changed = self.eng._growth(
-            planes, self.eng.gparts, jnp.int32(delta),
-            jnp.int32(half_target), jnp.int32(num_it), variant=variant,
-        )
-        state = state._replace(d=planes[0], c=planes[1], pathw=planes[2])
-        return state, GrowthStats(steps=k, reached=reached,
-                                  changed_last=changed)
+        return _sharded_growth(self.eng, state, self.eng.gparts, delta,
+                               half_target, num_it, variant)
 
     # -- wire-byte accounting (read by engine._comm_accounting) ----------
 

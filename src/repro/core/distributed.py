@@ -31,10 +31,9 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.common import ceil_div, get_logger, next_multiple
-from repro.common.compat import shard_map
 from repro.core.state import EngineState, INF
 from repro.graph.segment_ops import segment_min_triple
 from repro.graph.structures import EdgeList
@@ -217,7 +216,12 @@ class DistributedEngine:
         axis_names: Optional[Tuple[str, ...]] = None,
         graph: Optional[ShardedGraph] = None,
     ):
-        self.mesh = mesh
+        # the planes leave the shard_map bodies into plain jnp code (the
+        # engine's stage logic, the quotient pass), which relies on
+        # propagated shardings: re-type the axes Auto, whatever the caller's
+        # mesh says (jax.make_mesh makes them Explicit)
+        self.mesh = Mesh(mesh.devices, mesh.axis_names,
+                         axis_types=(AxisType.Auto,) * len(mesh.axis_names))
         self.axes = tuple(axis_names or mesh.axis_names)
         self.n_devices = int(np.prod([mesh.shape[a] for a in self.axes]))
         self.comm = comm
@@ -330,7 +334,7 @@ class DistributedEngine:
             if comm == "halo":
                 in_specs += [P(axes, None, None)] + [P(axes, None)] * 3
                 args += [send_ids, recv_slot, is_loc, loc_idx]
-            nd, nc, npw, ch = shard_map(
+            nd, nc, npw, ch = jax.shard_map(
                 body, mesh=self.mesh, in_specs=tuple(in_specs),
                 out_specs=out_specs, check_vma=False,
             )(*args)

@@ -545,8 +545,8 @@ def _sssp_from(session: GraphSession, source: int, delta: Optional[int]):
     (dist, supersteps, inf) — the distance dtype follows the same provable
     bound as ``sssp.bellman_ford`` (int64 when ``n * max_weight`` would
     overflow int32, so heavy-weight graphs never wrap negative)."""
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.core.sssp import _bf_loop, _delta_stepping_loop, sssp_dtype_for
 
@@ -554,7 +554,7 @@ def _sssp_from(session: GraphSession, source: int, delta: Optional[int]):
     src, dst, w = session.flat_device_edges()
     # dtype: delta=None means unbucketed; None and 0 pick the same bound
     dtype, inf = sssp_dtype_for(n, session.max_weight, delta or 0)
-    with enable_x64(), telemetry.span("sssp.solve", source=source) as sp:
+    with jax.enable_x64(True), telemetry.span("sssp.solve", source=source) as sp:
         infj = jnp.asarray(inf, dtype)
         d0 = jnp.full(n, infj, dtype=dtype).at[source].set(0)
         wd = w.astype(dtype)

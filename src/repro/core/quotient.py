@@ -15,7 +15,7 @@ solved locally in O(1) rounds. We mirror that fully on device:
     SingleDevice/Sharded/Pallas through ``backend.quotient_args()``.
   * ``_solve_kernel`` — batched multi-source SSSP (``sssp.batched_bf_loop``
     vmapped over all quotient sources), int64-safe (traced under
-    ``jax.experimental.enable_x64``), returning
+    ``jax.enable_x64(True)``), returning
     (diameter, eccentricities, connected) in ONE packed fetch.
 
 scipy APSP (``quotient_diameter``) is kept as the test oracle only; the
@@ -31,7 +31,6 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.analysis import guard
 from repro.common import next_multiple
@@ -116,7 +115,7 @@ def _quotient_kernel(src, dst, w, mask, final_c, final_pathw, *, n: int):
     """One segment-ops pass: cross-edge detect -> key sort -> coalesce.
 
     ``src``/``dst`` may contain phantom ids >= n (Pallas/sharded padding);
-    ``mask`` marks real edges. Traced under enable_x64, so the quotient
+    ``mask`` marks real edges. Traced under jax.enable_x64, so the quotient
     weight (a sum of three int32 terms) is exact int64.
     """
     E = src.shape[0]
@@ -136,7 +135,10 @@ def _quotient_kernel(src, dst, w, mask, final_c, final_pathw, *, n: int):
     key_inf = jnp.int64(INF64)
     key = jnp.where(
         cross, cu.astype(jnp.int64) * (n + 1) + cv.astype(jnp.int64), key_inf)
-    order = jnp.lexsort((wq, key))
+    # grouping by key is all the coalesce needs (the tuple-min below takes
+    # each group's lightest edge); a one-key sort also compiles faster
+    # for the TPU than a two-key one
+    order = jnp.argsort(key)
     key_s, wq_s = key[order], wq[order]
     cu_s, cv_s = cu[order], cv[order]
     valid_s = key_s < key_inf
@@ -167,7 +169,7 @@ def fetch_quotient_counters(dq: DeviceQuotient) -> Tuple[int, int, int, int]:
     """ONE packed host fetch of the four device counters:
     ``(n_clusters, n_edges, max_weight, weight_sum)``. Callers account the
     sync (``PipelineMetrics.quotient_syncs``) themselves."""
-    with enable_x64():
+    with jax.enable_x64(True):
         kmws = guard.fetch(jnp.stack([
             dq.n_clusters.astype(jnp.int64), dq.n_edges.astype(jnp.int64),
             dq.max_weight, dq.weight_sum]),
@@ -208,7 +210,7 @@ def build_quotient_device(
     else:
         src, dst, w, mask = _flat_quotient_args(edges)
     fc, fp = _decomposition_planes(dec, n)
-    with enable_x64():
+    with jax.enable_x64(True):
         return _quotient_kernel(src, dst, w, mask, fc, fp, n=n)
 
 
@@ -228,7 +230,7 @@ def build_quotient(edges: EdgeList, dec: Decomposition, backend=None) -> Quotien
             src=z, dst=z, weight=z.astype(np.int64))
     k, m = map(int, guard.fetch(jnp.stack([dq.n_clusters, dq.n_edges]),
                                 reason="host quotient: (k, m) counters"))
-    with enable_x64():  # int64 arrays must be sliced with x64 tracing on
+    with jax.enable_x64(True):  # int64 arrays must be sliced with x64 tracing on
         return QuotientGraph(
             n_clusters=k,
             center_ids=np.asarray(dq.centers[:k]),
@@ -267,7 +269,7 @@ class QuotientLevel(NamedTuple):
         """Host materialization (tests / oracles): the first ``n_edges``
         slots are exactly the coalesced quotient edges."""
         m = self.n_edges
-        with enable_x64():
+        with jax.enable_x64(True):
             return EdgeList(
                 self.n_nodes,
                 np.asarray(self.src[:m]), np.asarray(self.dst[:m]),
@@ -279,7 +281,7 @@ def _level_edges_kernel(src, dst, w, scale):
     """Rewrite sliced DeviceQuotient buffers as engine-ready edges: valid
     slots keep their endpoints with ceil-rescaled int32 weight, invalid
     slots (weight >= INF64, incl. the empty-segment int64-max fill) become
-    inert self-loops. Traced under enable_x64 (w is int64)."""
+    inert self-loops. Traced under jax.enable_x64 (w is int64)."""
     valid = w < jnp.int64(INF64)
     w32 = jnp.where(valid, (w + scale - 1) // scale, jnp.int64(1))
     w32 = jnp.clip(w32, 1, jnp.int64(int(MAX_WEIGHT))).astype(jnp.int32)
@@ -303,7 +305,7 @@ def quotient_as_edgelist(
     scale = weight_scale_for(max_weight)
     E = dq.src.shape[0]
     e_pad = min(next_multiple(max(m, 1), edge_bucket), max(E, 1))
-    with enable_x64():
+    with jax.enable_x64(True):
         src, dst, w32 = _level_edges_kernel(
             dq.src[:e_pad], dq.dst[:e_pad], dq.weight[:e_pad],
             jnp.int64(scale))
@@ -319,7 +321,7 @@ def build_quotient_from_level(level: QuotientLevel, dec: Decomposition
     self-loops are never cross edges, so no mask is needed beyond ones."""
     fc, fp = _decomposition_planes(dec, level.n_nodes)
     mask = jnp.ones(level.src.shape, dtype=bool)
-    with enable_x64():
+    with jax.enable_x64(True):
         return _quotient_kernel(level.src, level.dst, level.weight, mask,
                                 fc, fp, n=level.n_nodes)
 
@@ -341,7 +343,7 @@ def _merge_quotient_kernel(cs, cd, cw, fs, fd, fw, dirty_compact, *, n: int):
     exactly the dirty-incident edge slice — cover all such pairs, so the
     two sets are DISJOINT by construction and a key sort (no re-coalesce)
     restores the ``DeviceQuotient`` sorted-key invariant. Traced under
-    enable_x64 (weights are int64).
+    jax.enable_x64 (weights are int64).
     """
     drop = (dirty_compact[jnp.clip(cs, 0, n - 1)]
             | dirty_compact[jnp.clip(cd, 0, n - 1)])
@@ -383,7 +385,7 @@ def quotient_update_device(
     full rebuild of the quotient).
     """
     sub_src, sub_dst, sub_w, sub_mask = dirty_edge_args
-    with enable_x64():
+    with jax.enable_x64(True):
         fresh = _quotient_kernel(sub_src, sub_dst, sub_w, sub_mask,
                                  final_c_dev, final_pathw_dev, n=n)
         dirty_node = np.zeros(n + 1, bool)
@@ -456,7 +458,7 @@ def solve_device_quotient(
     E = dq.src.shape[0]
     m_pad = min(next_multiple(max(m, 1), 8 * K_BUCKET), E)
     int32_safe = k_pad * max(int(max_weight), 1) < 2**31 - 1
-    with enable_x64():
+    with jax.enable_x64(True):
         qw = dq.weight[:m_pad]
         if int32_safe:
             # invalid (padding) slots carry INF64 -> map onto the int32 INF
@@ -482,7 +484,7 @@ def quotient_diameter_device(q: QuotientGraph) -> Tuple[int, np.ndarray, bool]:
     dst = np.concatenate([q.dst, q.src]).astype(np.int32)
     w = np.concatenate([q.weight, q.weight]).astype(np.int64)
     wmax = int(w.max()) if len(w) else 0
-    with enable_x64():
+    with jax.enable_x64(True):
         dq = DeviceQuotient(
             centers=jnp.asarray(q.center_ids.astype(np.int32)),
             src=jnp.asarray(src), dst=jnp.asarray(dst), weight=jnp.asarray(w),
@@ -522,7 +524,7 @@ def quotient_diameter_minplus(q: QuotientGraph) -> Tuple[int, bool]:
     """jnp min-plus matrix-squaring fallback (cross-checks scipy in tests
     and serves as the device-local path when scipy is unavailable).
 
-    int64-safe: the squaring runs under enable_x64 with guarded adds, so
+    int64-safe: the squaring runs under jax.enable_x64 with guarded adds, so
     weights above 2^24 (which float32 silently rounds) stay exact. Shares
     the (diameter, connected) contract with ``quotient_diameter`` — a
     disconnected quotient is flagged instead of reporting a finite max.
@@ -536,7 +538,7 @@ def quotient_diameter_minplus(q: QuotientGraph) -> Tuple[int, bool]:
     np.minimum.at(m, (q.dst, q.src), q.weight.astype(np.int64))
     np.fill_diagonal(m, 0)
 
-    with enable_x64():
+    with jax.enable_x64(True):
         d = jnp.asarray(m)
         steps = int(np.ceil(np.log2(max(k - 1, 1)))) or 1
         for _ in range(steps):

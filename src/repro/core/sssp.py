@@ -15,7 +15,7 @@
 
 Distance dtype is picked from a provable bound (``sssp_dtype_for``): every
 shortest path has < n edges, so when ``n * max_weight`` fits int32 the
-loops run in int32; otherwise they run in int64 under ``enable_x64``
+loops run in int32; otherwise they run in int64 under ``jax.enable_x64``
 (legal edge weights go up to 2^30 - 1, which overflows int32 after a
 handful of hops — the old int32-only loops silently wrapped negative and
 reported false minima). ``SSSPResult.inf`` carries the unreached sentinel
@@ -40,14 +40,16 @@ import numpy as np
 from repro.analysis import guard
 from repro.graph.structures import EdgeList
 
-INF = jnp.int32(2**31 - 1)
+# numpy scalars, not jax arrays: a jax array closed over by a traced
+# function is copied device->host when the program is lowered
+INF = np.int32(2**31 - 1)
 INF64 = 2**62  # int64 unreached sentinel; guarded adds stay < 2^63
 
 
 def sssp_dtype_for(n_nodes: int, max_weight: int, delta: int = 0):
     """(dtype, inf) from the provable distance bound: every shortest path
     has < n edges, so distances are < n * max_weight. int32 fast path when
-    that fits, int64 (under enable_x64) otherwise.
+    that fits, int64 (under jax.enable_x64) otherwise.
 
     ``delta``: headroom for Δ-stepping's bucket bound ``(b + 1) * delta``
     — it can exceed the largest distance by up to one bucket, so bucketed
@@ -102,12 +104,11 @@ def _edge_arrays(edges: EdgeList, dtype):
 
 
 def bellman_ford(edges: EdgeList, source: int) -> SSSPResult:
-    from jax.experimental import enable_x64
 
     n = edges.n_nodes
     wmax = int(edges.weight.max()) if edges.n_edges else 1
     dtype, inf = sssp_dtype_for(n, wmax)
-    with enable_x64():
+    with jax.enable_x64(True):
         infj = jnp.asarray(inf, dtype)
         d0 = jnp.full(n, infj, dtype=dtype).at[source].set(0)
         d, k = _bf_loop(*_edge_arrays(edges, dtype), d0, infj, n)
@@ -124,7 +125,7 @@ def batched_bf_loop(src, dst, w, d0, inf, n_nodes: int):
     contiguous row-gather ``d[src]`` plus one ND ``segment_min`` (row-wise
     scatter), which XLA vectorizes ~5x better than a vmap of per-source
     scalar scatters. ``inf`` is the unreached sentinel in d0's dtype
-    (int64-safe: callers trace this under ``jax.experimental.enable_x64``
+    (int64-safe: callers trace this under ``jax.enable_x64(True)``
     with ``inf < dtype_max / 2`` so the guarded add never overflows).
     Padding edges are expressed as ``w >= inf`` and never relax. The loop
     runs until no distance changes anywhere in the batch. Returns
@@ -156,13 +157,12 @@ def multi_source_bellman_ford(edges: EdgeList, sources) -> MultiSSSPResult:
     Distance dtype is picked by ``sssp_dtype_for`` from the same provable
     bound as the single-source loops.
     """
-    from jax.experimental import enable_x64
 
     n = edges.n_nodes
     sources = np.asarray(sources, dtype=np.int32)
     wmax = int(edges.weight.max()) if edges.n_edges else 1
     dtype, inf = sssp_dtype_for(n, wmax)
-    with enable_x64():
+    with jax.enable_x64(True):
         infj = jnp.asarray(inf, dtype)
         d0 = jnp.full((n, len(sources)), infj, dtype=dtype)
         d0 = d0.at[jnp.asarray(sources), jnp.arange(len(sources))].set(0)
@@ -242,12 +242,11 @@ def _delta_stepping_loop(src, dst, w, d0, delta, inf, n_nodes: int):
 
 
 def delta_stepping(edges: EdgeList, source: int, delta: int) -> SSSPResult:
-    from jax.experimental import enable_x64
 
     n = edges.n_nodes
     wmax = int(edges.weight.max()) if edges.n_edges else 1
     dtype, inf = sssp_dtype_for(n, wmax, delta)
-    with enable_x64():
+    with jax.enable_x64(True):
         infj = jnp.asarray(inf, dtype)
         d0 = jnp.full(n, infj, dtype=dtype).at[source].set(0)
         d, k = _delta_stepping_loop(

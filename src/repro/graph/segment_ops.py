@@ -15,8 +15,11 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-INF = jnp.int32(2**31 - 1)
+# numpy scalars, not jax arrays: a jax array closed over by a traced
+# function is copied device->host when the program is lowered
+INF = np.int32(2**31 - 1)
 
 
 def _sentinel(x: jnp.ndarray):

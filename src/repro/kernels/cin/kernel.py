@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.common.compat import tpu_compiler_params
 
 
 def _cin_kernel(x0_ref, xk_ref, w_ref, out_ref, *, m: int, h: int):
@@ -64,7 +63,7 @@ def cin_layer_pallas(
         out_specs=pl.BlockSpec((1, H2, dt), lambda b, d: (b, 0, d)),
         out_shape=jax.ShapeDtypeStruct((B, H2, D), x0.dtype),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
     )(x0, xk, w_flat)

@@ -15,7 +15,12 @@ Layout contract (produced by ``graph.structures.DeviceGraph.build``):
     Pallas can map output blocks before the body runs);
 
 Grid: one step per edge block (sequential — "arbitrary" dimension semantics),
-output node-tile block revisited by consecutive steps. The within-block
+output node-tile block revisited by consecutive steps. The edge arrays are
+laid out ``[n_blocks, 1, edge_block]`` (``edge_slabs``) and the outputs
+``[n_tiles, 1, node_tile]``, each grid step taking one squeezed ``(1, ·)``
+slab: the TPU compiler (Mosaic) requires the last two block dims to be
+divisible by (8, 128) or equal to the array's, which a ``(1, edge_block)``
+block of a ``[n_blocks, edge_block]`` array is not. The within-block
 reduce-by-key is a broadcast-compare + row-min over a [node_tile, edge_block]
 match matrix: a VPU-native realization of the scatter that would be a serial
 loop on TPU. int32 throughout.
@@ -29,10 +34,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.common.compat import tpu_compiler_params
-
-INF = jnp.int32(2**31 - 1)
-BIG = jnp.int32(2**30)
 
 # default tiling: 256-node tiles, 512-edge blocks -> match matrix 256x512
 NODE_TILE = 256
@@ -75,13 +76,23 @@ def validate_block_tile(block_tile, n_tiles: int) -> None:
             "each tile's partial tuple-min across consecutive edge blocks")
 
 
+def edge_slabs(x, edge_block: int):
+    """``[n_blocks, 1, edge_block]`` view of blocked per-edge data (a jax or
+    numpy array): the layout the kernels stream. Free when ``x`` already has
+    it; on TPU a ``[n_blocks, edge_block]`` array is tiled differently, so
+    reshaping one costs a copy — ``PallasBackend`` uploads its edges in this
+    layout so no superstep pays it."""
+    return x.reshape(x.shape[0], 1, edge_block)
+
+
 def _relax_kernel(
     # scalar-prefetch
     block_tile,            # int32 [n_blocks]  node tile of each edge block
     delta_ref,             # int32 [1]
-    # per-edge inputs, blocked [1, EDGE_BLOCK]
+    # per-edge inputs, one [1, EDGE_BLOCK] slab of [n_blocks, 1, EDGE_BLOCK]
     d_src, c_src, p_src, rw0, rc, rp, w, dst, mask,
-    # outputs, blocked [1, NODE_TILE] (revisited across a tile's blocks)
+    # outputs, one [1, NODE_TILE] slab of [n_tiles, 1, NODE_TILE]
+    # (revisited across a tile's blocks)
     d_out, c_out, p_out,
     *, node_tile: int, edge_block: int,
 ):
@@ -92,7 +103,7 @@ def _relax_kernel(
     tile = block_tile[b]
 
     # --- candidate computation (VPU elementwise) -------------------------
-    dsv, wv, mk = d_src[0], w[0], mask[0]
+    dsv, wv, mk = d_src[0], w[0], mask[0] != 0
     rw0v = rw0[0]
     live_ok = (dsv < delta) & (wv < delta) & mk
     live_d = jnp.where(live_ok, jnp.where(live_ok, dsv, 0) + wv, INF)
@@ -138,7 +149,7 @@ def _relax_kernel(
     static_argnames=("n_tiles", "node_tile", "edge_block", "interpret"),
 )
 def _edge_relax_pallas_jit(
-    d_src: jnp.ndarray,     # int32 [n_blocks, EDGE_BLOCK] pre-gathered planes
+    d_src: jnp.ndarray,     # int32 [n_blocks, (1,) EDGE_BLOCK] gathered planes
     c_src: jnp.ndarray,
     p_src: jnp.ndarray,
     rw0: jnp.ndarray,
@@ -156,10 +167,13 @@ def _edge_relax_pallas_jit(
 ):
     """Fused relax + lexicographic segment-min. Returns (d, c, p) [n_tiles*T]."""
     n_blocks = d_src.shape[0]
-    mask = mask.astype(jnp.bool_)
+    e3 = lambda x: edge_slabs(x, edge_block)
+    edge_args = (e3(d_src), e3(c_src), e3(p_src), e3(rw0), e3(rc), e3(rp),
+                 e3(w), e3(dst), e3(mask))
 
-    edge_spec = pl.BlockSpec((1, edge_block), lambda b, *_: (b, 0))
-    out_spec = pl.BlockSpec((1, node_tile), lambda b, bt, _d: (bt[b], 0))
+    edge_spec = pl.BlockSpec((None, 1, edge_block), lambda b, *_: (b, 0, 0))
+    out_spec = pl.BlockSpec((None, 1, node_tile),
+                            lambda b, bt, _d: (bt[b], 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -168,7 +182,8 @@ def _edge_relax_pallas_jit(
         out_specs=[out_spec] * 3,
     )
     out_shape = [
-        jax.ShapeDtypeStruct((n_tiles, node_tile), jnp.int32) for _ in range(3)
+        jax.ShapeDtypeStruct((n_tiles, 1, node_tile), jnp.int32)
+        for _ in range(3)
     ]
     kern = functools.partial(_relax_kernel, node_tile=node_tile, edge_block=edge_block)
     d, c, p = pl.pallas_call(
@@ -176,10 +191,10 @@ def _edge_relax_pallas_jit(
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
-    )(block_tile, delta, d_src, c_src, p_src, rw0, rc, rp, w, dst, mask)
+    )(block_tile, delta, *edge_args)
     return d.reshape(-1), c.reshape(-1), p.reshape(-1)
 
 

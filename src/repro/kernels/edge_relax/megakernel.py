@@ -38,6 +38,12 @@ block is processed at least once per grow call.
 remains the byte-identical parity oracle; the megakernel parity suite
 (``tests/test_megakernel.py``) runs this kernel in interpret mode on CPU.
 
+Interpret mode is the only way this kernel runs today: the TPU compiler
+(Mosaic) refuses the in-kernel source gathers ``ref[...].reshape(-1)[srcv]``
+with "Only 2D gather is supported", so ``PallasBackend`` rejects ``fuse > 0``
+with any ``impl`` other than ``"interpret"``. Returning it to the chip means
+dropping that 1-D gather first.
+
 VMEM contract: 15 int32 planes of ``n_pad`` slots stay resident (8 inputs,
 4 outputs, 3 accumulator scratch) plus the [node_tile, edge_block] match
 matrix. ``fits_vmem`` checks the footprint against a conservative budget;
@@ -53,7 +59,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.common.compat import tpu_compiler_params
+from repro.kernels.edge_relax.kernel import edge_slabs
+
 
 # stats layout: one row per fused superstep + one summary row (index K).
 # Per-superstep rows: executed flag, nodes changed, reached count after the
@@ -97,7 +104,7 @@ def _mega_kernel(
                            #            steps_base, stop_variant, ...
     # resident inputs [n_tiles, node_tile]
     d0, c0, p0, rw0, rc, rp, frozen, front0,
-    # per-edge inputs, blocked [1, edge_block] along grid dim 1
+    # per-edge inputs, one [1, edge_block] slab per step of grid dim 1
     bsrc, bdst, bw, bmask,
     # resident outputs
     d, c, p, front,        # [n_tiles, node_tile]
@@ -184,14 +191,14 @@ def _mega_kernel(
         p_blk = jnp.min(jnp.where(w2, cand_p[None, :], INF), axis=1)
         # lexicographic merge into the owning tile's accumulator row
         idx = (pl.ds(tile, 1), pl.ds(0, node_tile))
-        ad = pl.load(acc_d, idx)[0]
-        ac = pl.load(acc_c, idx)[0]
-        ap = pl.load(acc_p, idx)[0]
+        ad = acc_d[idx][0]
+        ac = acc_c[idx][0]
+        ap = acc_p[idx][0]
         take = (d_blk < ad) | ((d_blk == ad) & (
             (c_blk < ac) | ((c_blk == ac) & (p_blk < ap))))
-        pl.store(acc_d, idx, jnp.where(take, d_blk, ad)[None])
-        pl.store(acc_c, idx, jnp.where(take, c_blk, ac)[None])
-        pl.store(acc_p, idx, jnp.where(take, p_blk, ap)[None])
+        acc_d[idx] = jnp.where(take, d_blk, ad)[None]
+        acc_c[idx] = jnp.where(take, c_blk, ac)[None]
+        acc_p[idx] = jnp.where(take, p_blk, ap)[None]
 
     @pl.when(running & ~live_block)
     def _dead_block():
@@ -218,7 +225,7 @@ def _mega_kernel(
             row = row.at[COL_REACHED].set(reached)
             row = row.at[COL_DEAD].set(flags[3])
             row = row.at[COL_CONT].set(cont.astype(jnp.int32))
-            pl.store(stats, (pl.ds(k, 1), pl.ds(0, STATS_W)), row[None])
+            stats[pl.ds(k, 1), pl.ds(0, STATS_W)] = row[None]
 
         @pl.when(k == pl.num_programs(0) - 1)
         def _summary():
@@ -230,8 +237,7 @@ def _mega_kernel(
             row = row.at[COL_REACHED].set(reached)
             row = row.at[COL_DEAD].set(flags[3])
             row = row.at[COL_CONT].set(cont.astype(jnp.int32))
-            pl.store(stats, (pl.ds(pl.num_programs(0), 1),
-                             pl.ds(0, STATS_W)), row[None])
+            stats[pl.ds(pl.num_programs(0), 1), pl.ds(0, STATS_W)] = row[None]
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -245,7 +251,7 @@ def fused_grow_supersteps(
     rp: jnp.ndarray,
     frozen: jnp.ndarray,     # int32 0/1
     front: jnp.ndarray,      # int32 0/1 frontier bitmap (carried)
-    bsrc: jnp.ndarray,       # [n_blocks, edge_block] blocked edges
+    bsrc: jnp.ndarray,       # [n_blocks, (1,) edge_block] blocked edges
     bdst: jnp.ndarray,
     bw: jnp.ndarray,
     bmask: jnp.ndarray,
@@ -264,7 +270,8 @@ def fused_grow_supersteps(
     """
     n_blocks = bsrc.shape[0]
     plane_spec = pl.BlockSpec((n_tiles, node_tile), lambda k, b, *_: (0, 0))
-    edge_spec = pl.BlockSpec((1, edge_block), lambda k, b, *_: (b, 0))
+    edge_spec = pl.BlockSpec((None, 1, edge_block),
+                             lambda k, b, *_: (b, 0, 0))
     stats_spec = pl.BlockSpec((k_fused + 1, STATS_W), lambda k, b, *_: (0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -289,11 +296,12 @@ def fused_grow_supersteps(
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
     )(block_tile, params, d, c, p, rw0, rc, rp, frozen, front,
-      bsrc, bdst, bw, bmask)
+      edge_slabs(bsrc, edge_block), edge_slabs(bdst, edge_block),
+      edge_slabs(bw, edge_block), edge_slabs(bmask, edge_block))
 
 
 def megakernel_growth_loop(

@@ -3,12 +3,11 @@
 ``edge_relax(...)`` takes flat destination-sorted per-edge arrays (the layout
 ``DeviceGraph.build`` produces, or any dst-sorted edge list — this wrapper
 re-blocks on the fly), pre-gathers the source planes, dispatches to the
-Pallas kernel (TPU) or the jnp oracle (CPU / explicit ``impl="ref"``), and
-returns per-node (d_min, c_min, p_min).
+Pallas kernel (``impl="pallas"``, TPU only; ``"interpret"`` anywhere) or
+the jnp oracle (``impl="ref"``), and returns per-node (d_min, c_min, p_min).
 """
 from __future__ import annotations
 
-import warnings
 from functools import partial
 from typing import Tuple
 
@@ -25,28 +24,19 @@ from repro.kernels.edge_relax.kernel import (
 from repro.kernels.edge_relax.ref import INF, edge_relax_ref
 
 
-def _default_impl() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
-
-
-_PALLAS_FALLBACK_WARNED = False
-
-
-def _resolve_impl(impl: str) -> str:
-    """Compiled-Pallas requests off TPU fall back to the jnp reference with
-    a one-time warning instead of failing at trace time (Mosaic lowering is
-    TPU-only; single-device CI runs on CPU). ``interpret`` is always legal —
-    it IS the CPU oracle path."""
-    global _PALLAS_FALLBACK_WARNED
+def resolve_impl(impl: str) -> str:
+    """Compiled Pallas needs a TPU: asking for it elsewhere is an error, not
+    a quiet switch to the reference. ``interpret`` runs the kernel body on
+    any backend and ``ref`` is the jnp oracle."""
     if impl == "pallas" and jax.default_backend() != "tpu":
-        if not _PALLAS_FALLBACK_WARNED:
-            _PALLAS_FALLBACK_WARNED = True
-            warnings.warn(
-                "edge_relax: impl='pallas' requested but the default JAX "
-                "backend is not TPU; falling back to the reference "
-                "implementation (use impl='interpret' to exercise the "
-                "kernel body on CPU)", RuntimeWarning, stacklevel=3)
-        return "ref"
+        raise RuntimeError(
+            "edge_relax: impl='pallas' needs a TPU, but the default JAX "
+            f"backend is {jax.default_backend()!r}; use impl='interpret' to "
+            "run the kernel body here or impl='ref' for the jnp oracle")
+    if impl not in ("pallas", "interpret", "ref"):
+        raise ValueError(
+            f"unknown edge_relax impl {impl!r} (expected pallas | interpret "
+            "| ref)")
     return impl
 
 
@@ -115,7 +105,7 @@ def block_edges_host(
 @partial(jax.jit, static_argnames=("n_tiles", "node_tile", "edge_block", "impl"))
 def edge_relax(
     planes: Tuple[jnp.ndarray, ...],  # (d, c, p, rw0, rc, rp) node planes [n_pad]
-    blocked_src: jnp.ndarray,         # [n_blocks, E_B]
+    blocked_src: jnp.ndarray,         # [n_blocks, E_B] or [n_blocks, 1, E_B]
     blocked_dst: jnp.ndarray,
     blocked_w: jnp.ndarray,
     blocked_mask: jnp.ndarray,
@@ -127,7 +117,7 @@ def edge_relax(
     impl: str = "ref",
 ):
     """One fused relaxation pass. Gathers source planes then reduces."""
-    impl = _resolve_impl(impl)
+    impl = resolve_impl(impl)
     d, c, p, rw0, rc, rp = planes
     g = lambda x: x[blocked_src]
     if impl == "pallas" or impl == "interpret":

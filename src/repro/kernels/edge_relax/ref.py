@@ -21,9 +21,12 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-INF = jnp.int32(2**31 - 1)
-BIG = jnp.int32(2**30)
+# numpy scalars, not jax arrays: a jax array closed over by a traced
+# function is copied device->host when the program is lowered
+INF = np.int32(2**31 - 1)
+BIG = np.int32(2**30)
 
 
 def edge_relax_candidates(

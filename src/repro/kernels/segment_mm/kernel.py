@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.common.compat import tpu_compiler_params
 
 NODE_TILE = 256
 EDGE_BLOCK = 512
@@ -89,7 +88,7 @@ def segment_mm_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_tiles * node_tile, d), x_src.dtype),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
     )(block_tile, x_src, coeff, dst)

@@ -21,7 +21,7 @@ import contextlib
 
 import jax
 
-from repro.common import get_logger
+from repro.common import enable_compile_cache, get_logger
 from repro.config.base import GraphEngineConfig
 from repro.core import (
     CascadeEstimator,
@@ -169,6 +169,7 @@ def main() -> int:
     validate_tau(ap, args.tau)
     validate_cascade(ap, args)
     check_engine_mode(args.engine_mode)  # before any graph/device work
+    enable_compile_cache()
     backend_kind = "sharded" if args.distributed else args.backend
 
     g = build_graph(args.graph, args.n, args.seed)

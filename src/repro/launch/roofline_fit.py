@@ -38,7 +38,7 @@ from repro.config.base import MoEConfig, TransformerConfig, shapes_for_family
 from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import _lm_model_flops, all_cells, build_cell
 from repro.runtime.roofline import (
-    HBM_BW, ICI_BW, PEAK_FLOPS, analyze, parse_collectives,
+    V5E, analyze, parse_collectives,
 )
 from repro.runtime.telemetry import clock
 
@@ -131,10 +131,10 @@ def fit_lm_cell(arch, shape_name, mesh, multi_pod, out_path):
 
     n_chips = int(np.prod([mesh.shape[a] for a in mesh.axis_names]))
     adj_bytes = _lm_hbm_bytes(cfg, shape_obj, n_chips)
-    t_comp = flops / (n_chips * PEAK_FLOPS)
-    t_mem_raw = nbytes / (n_chips * HBM_BW)
-    t_mem = adj_bytes / (n_chips * HBM_BW)
-    t_coll = coll / ICI_BW
+    t_comp = flops / (n_chips * V5E.flops)
+    t_mem_raw = nbytes / (n_chips * V5E.hbm_bw)
+    t_mem = adj_bytes / (n_chips * V5E.hbm_bw)
+    t_coll = coll / V5E.ici_bw
     bound = max(t_comp, t_mem, t_coll)
     row = {
         "name": f"{arch}/{shape}",
@@ -157,7 +157,7 @@ def fit_lm_cell(arch, shape_name, mesh, multi_pod, out_path):
                            "collective": t_coll}[k]),
         "useful_ratio": round(model_flops / flops, 3) if flops else 0.0,
         "roofline_frac": round(
-            (model_flops / (n_chips * PEAK_FLOPS)) / bound, 3) if bound else 0,
+            (model_flops / (n_chips * V5E.flops)) / bound, 3) if bound else 0,
         "probe_s": round(clock() - t0, 1),
         "coll_counts_probe_L4": f4[3],
     }

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.common import bench_engine_path, get_logger
+from repro.common import bench_engine_path, enable_compile_cache, get_logger
 from repro.config.registry import get_arch
 from repro.models import transformer as tf_mod
 from repro.runtime import telemetry
@@ -400,6 +400,7 @@ def main() -> int:
             ap.error(f"--sync-budget must be off | bench | <int> "
                      f"(got {args.sync_budget!r})")
 
+    enable_compile_cache()
     if args.mode == "graph-diameter":
         return serve_graph_diameter(args)
 

@@ -31,7 +31,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.common.compat import shard_map
 
 
 def _pack_by_destination(h, flat_dest, tok_idx, n_dest, cap, keep_extra=None):
@@ -138,7 +137,7 @@ def moe_ffn_a2a(
         y_tok = y_tok * (gate.reshape(-1) * keep.astype(jnp.float32))[:, None]
         return jax.ops.segment_sum(y_tok, tok_idx, num_segments=Tl)
 
-    out = shard_map(
+    out = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(flat, None), P(flat, None), P(flat, None),

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.common.compat import shard_map
 
 
 def gpipe_forward(
@@ -86,7 +85,7 @@ def gpipe_forward(
     other_axes = tuple(a for a in mesh.axis_names if a != pod_axis)
 
     def run(params_stacked, batch):
-        return shard_map(
+        return jax.shard_map(
             pipelined,
             mesh=mesh,
             in_specs=(P(pod_axis), P(other_axes[0] if other_axes else None)),

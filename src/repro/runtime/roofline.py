@@ -1,10 +1,11 @@
 """Roofline term extraction from a compiled dry-run artifact.
 
-Three terms per (arch x shape x mesh), in seconds (TPU v5e constants):
+Three terms per (arch x shape x mesh), in seconds, priced with the v5e
+entry of ``PEAKS`` (the dry-run models a v5e pod):
 
-  compute    = HLO_FLOPs / (chips x 197e12 bf16 FLOP/s)
-  memory     = HLO_bytes / (chips x 819e9  HBM B/s)
-  collective = collective_wire_bytes / (chips x 50e9 ICI B/s per link)
+  compute    = HLO_FLOPs / (chips x peak bf16 FLOP/s)
+  memory     = HLO_bytes / (chips x peak HBM B/s)
+  collective = collective_wire_bytes / (chips x ICI B/s per link)
 
 FLOPs/bytes come from compiled.cost_analysis(). Collective bytes are NOT in
 cost_analysis — we parse the optimized HLO text and sum operand sizes of
@@ -21,10 +22,34 @@ from typing import Dict, Optional
 
 import numpy as np
 
-# TPU v5e per-chip constants (from the assignment)
-PEAK_FLOPS = 197e12        # bf16
-HBM_BW = 819e9             # bytes/s
-ICI_BW = 50e9              # bytes/s per link
+
+@dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks."""
+    flops: float     # bf16 FLOP/s
+    hbm_bw: float    # HBM bytes/s
+    ici_bw: float    # chip-to-chip bytes/s per link
+
+
+# Keyed by ``jax.Device.device_kind``. Source: Google Cloud documentation,
+# "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of
+# inter-chip interconnect (four links of 50 GB/s).
+PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+V5E = PEAKS["TPU v5 lite"]
+
+
+def peaks_for(device_kind: str) -> ChipPeaks:
+    """The table entry for ``device_kind``; a kind not in the table is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; known: "
+            f"{sorted(PEAKS)}") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -123,15 +148,15 @@ class RooflineReport:
 
     @property
     def t_compute(self) -> float:
-        return self.hlo_flops / (self.n_chips * PEAK_FLOPS)
+        return self.hlo_flops / (self.n_chips * V5E.flops)
 
     @property
     def t_memory(self) -> float:
-        return self.hlo_bytes / (self.n_chips * HBM_BW)
+        return self.hlo_bytes / (self.n_chips * V5E.hbm_bw)
 
     @property
     def t_collective(self) -> float:
-        return self.collective.wire_bytes / ICI_BW
+        return self.collective.wire_bytes / V5E.ici_bw
 
     @property
     def bottleneck(self) -> str:
@@ -151,7 +176,7 @@ class RooflineReport:
         """Fraction of peak the dominant-resource time implies for the
         useful (model) FLOPs: model_time_at_peak / bound_time."""
         bound = max(self.t_compute, self.t_memory, self.t_collective)
-        ideal = (self.model_flops or self.hlo_flops) / (self.n_chips * PEAK_FLOPS)
+        ideal = (self.model_flops or self.hlo_flops) / (self.n_chips * V5E.flops)
         return ideal / bound if bound else 0.0
 
     def row(self) -> Dict[str, object]:

@@ -275,7 +275,7 @@ class TestPallasLint:
                     kernel,
                     grid=(4,),
                     scratch_shapes=[pltpu.VMEM((8, 128), jnp.int32)],
-                    compiler_params=pltpu.TPUCompilerParams(
+                    compiler_params=pltpu.CompilerParams(
                         dimension_semantics=("arbitrary",)),
                 )(x)
         """)

@@ -21,6 +21,7 @@ from repro.core.autotune import (
 from repro.core.session import open_session
 from repro.config.base import GraphEngineConfig
 from repro.graph.structures import EdgeList
+from repro.runtime.roofline import V5E
 
 
 @pytest.fixture(autouse=True)
@@ -87,8 +88,8 @@ def test_signature_is_stable_and_shape_sensitive():
 def test_derive_tuning_is_valid_across_shapes():
     for n, e, wmax in [(50, 100, 3), (2000, 8000, 100), (500, 4000, 2**28)]:
         stats = compute_graph_stats(_edges(n, e, wmax, seed=n))
-        rec = derive_tuning(stats)
-        validate_tuning(rec, stats)  # must not raise
+        rec = derive_tuning(stats, peaks=V5E)
+        validate_tuning(rec, stats, V5E)  # must not raise
         assert 4 <= rec.tau <= n
         assert rec.tau_solve >= 64 and rec.levels in (0, 1, 2)
         assert 1 <= rec.delta_init < 2**30
@@ -103,14 +104,16 @@ def test_derive_tuning_hub_skew_doubles_tau():
     hub_dst = r.integers(0, n, e).astype(np.int32)
     hub_dst[: e // 2] = 0  # one node takes half the edges
     hub = EdgeList(n, flat.src, hub_dst, flat.weight)
-    t_flat = derive_tuning(compute_graph_stats(flat))
-    t_hub = derive_tuning(compute_graph_stats(hub))
+    t_flat = derive_tuning(compute_graph_stats(flat), peaks=V5E)
+    t_hub = derive_tuning(compute_graph_stats(hub), peaks=V5E)
     assert t_hub.tau == 2 * t_flat.tau
 
 
 def test_derive_tuning_delta_tracks_median_weight():
-    light = derive_tuning(compute_graph_stats(_edges(wmax=3, seed=1)))
-    heavy = derive_tuning(compute_graph_stats(_edges(wmax=2**20, seed=1)))
+    light = derive_tuning(compute_graph_stats(_edges(wmax=3, seed=1)),
+                          peaks=V5E)
+    heavy = derive_tuning(compute_graph_stats(_edges(wmax=2**20, seed=1)),
+                          peaks=V5E)
     assert light.delta_init < heavy.delta_init
     # heavy-tailed: median-based delta sits far below the mean-based "avg"
     skewed = _edges(seed=4)
@@ -118,13 +121,13 @@ def test_derive_tuning_delta_tracks_median_weight():
     w[:20] = 2**29  # 1% giants drag the mean up ~4 orders of magnitude
     stats = compute_graph_stats(EdgeList(skewed.n_nodes, skewed.src,
                                          skewed.dst, w))
-    rec = derive_tuning(stats)
+    rec = derive_tuning(stats, peaks=V5E)
     assert rec.delta_init < stats.avg_weight
 
 
 def test_validate_tuning_rejects_stale_records():
     stats = compute_graph_stats(_edges())
-    rec = derive_tuning(stats)
+    rec = derive_tuning(stats, peaks=V5E)
     for bad in (
         dataclasses.replace(rec, edge_block=100),       # kernel precondition
         dataclasses.replace(rec, tau=0),
@@ -134,24 +137,40 @@ def test_validate_tuning_rejects_stale_records():
         dataclasses.replace(rec, fuse=-1),
     ):
         with pytest.raises((AutotuneError, ValueError)):
-            validate_tuning(bad, stats)
+            validate_tuning(bad, stats, V5E)
 
 
 def test_validate_tuning_rejects_roofline_regression():
     # a graph large enough that the tiling choice matters: a wildly padded
     # alternative must fail the 1.05x roofline check
     stats = compute_graph_stats(_edges(n=20000, e=60000, seed=9))
-    rec = derive_tuning(stats)
+    rec = derive_tuning(stats, peaks=V5E)
     worst = None
     for nt in autotune.NODE_TILE_CANDIDATES:
         for eb in autotune.EDGE_BLOCK_CANDIDATES:
-            t, _ = autotune._tiling_time(stats.n_nodes, stats.n_edges, nt, eb)
+            t, _ = autotune._tiling_time(stats.n_nodes, stats.n_edges, nt, eb,
+                                          V5E)
             if worst is None or t > worst[2]:
                 worst = (nt, eb, t)
     assert worst[2] > rec.predicted_superstep_s * 1.05
     stale = dataclasses.replace(rec, node_tile=worst[0], edge_block=worst[1])
     with pytest.raises(AutotuneError, match="stale"):
-        validate_tuning(stale, stats)
+        validate_tuning(stale, stats, V5E)
+
+
+def test_peaks_table_is_keyed_by_device_kind():
+    from repro.runtime.roofline import PEAKS, peaks_for
+
+    assert peaks_for("TPU v5 lite") is V5E is PEAKS["TPU v5 lite"]
+    assert (V5E.flops, V5E.hbm_bw) == (197e12, 819e9)
+    with pytest.raises(KeyError, match="TPU v9 imaginary"):
+        peaks_for("TPU v9 imaginary")
+
+
+def test_derive_tuning_never_fuses():
+    for n, e in [(500, 2000), (20000, 60000)]:
+        stats = compute_graph_stats(_edges(n=n, e=e, seed=n))
+        assert derive_tuning(stats, peaks=V5E).fuse == 0
 
 
 # ---------------------------------------------------------------------------
@@ -166,7 +185,7 @@ def test_get_tuning_caches_by_signature():
     assert autotune.TUNE_EVENTS == {"hits": 1, "misses": 1}
     get_tuning(_edges(seed=6))
     assert autotune.TUNE_EVENTS["misses"] == 2
-    # backend is part of the key: pallas may fuse where single cannot
+    # backend is part of the key
     get_tuning(edges, backend="pallas")
     assert autotune.TUNE_EVENTS["misses"] == 3
 
@@ -192,7 +211,7 @@ def test_loaded_record_survives_dataclass_round_trip(tmp_path):
     load_cache(path)
     (rec,) = autotune._CACHE.values()
     assert isinstance(rec, TuningRecord)
-    validate_tuning(rec, compute_graph_stats(_edges(seed=8)))
+    validate_tuning(rec, compute_graph_stats(_edges(seed=8)), V5E)
 
 
 # ---------------------------------------------------------------------------

@@ -176,11 +176,11 @@ def test_property_cascade_bracket(n, ef, seed, levels, wmax):
 # ---------------------------------------------------------------------------
 
 def test_quotient_as_edgelist_rescales_and_inerts_padding():
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     heavy = 3 * int(MAX_WEIGHT)  # int64-only quotient weight
-    with enable_x64():
+    with jax.enable_x64(True):
         dq = DeviceQuotient(
             centers=jnp.arange(3, dtype=jnp.int32),
             src=jnp.asarray([0, 1, 2, 7], jnp.int32),

@@ -167,7 +167,6 @@ def test_int8_allreduce_shardmap():
     out = _run("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.common.compat import shard_map
     from repro.runtime.compression import int8_allreduce_shardmap
     mesh = jax.make_mesh((4,), ("data",))
     reduce_fn = int8_allreduce_shardmap(mesh, "data")
@@ -177,7 +176,7 @@ def test_int8_allreduce_shardmap():
     def f(x):
         return reduce_fn({"g": x})["g"]
 
-    out = jax.jit(shard_map(f, mesh=mesh, in_specs=P("data"),
+    out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("data"),
                             out_specs=P("data"), check_vma=False))(local)
     want = jnp.broadcast_to(local.mean(0, keepdims=True), local.shape)
     rel = float(jnp.abs(out - want).max() / (jnp.abs(want).max() + 1e-9))

@@ -76,9 +76,9 @@ def _assert_grow_parity(edges, delta, num_it, variant, seed, k_fused=4,
                         node_tile=256, edge_block=512):
     """fused (megakernel, interpret) vs unfused (ref growth_loop) on the
     SAME blocked layout and the SAME seeded state."""
-    kw = dict(impl="ref", node_tile=node_tile, edge_block=edge_block)
-    be_ref = PallasBackend(edges, **kw)
-    be_mk = PallasBackend(edges, fuse=k_fused, **kw)
+    kw = dict(node_tile=node_tile, edge_block=edge_block)
+    be_ref = PallasBackend(edges, impl="ref", **kw)
+    be_mk = PallasBackend(edges, impl="interpret", fuse=k_fused, **kw)
     assert be_mk.fuse == k_fused
     st0 = _seed_growth_state(be_ref, seed)
     half = jnp.int32(max(edges.n_nodes // 2, 1))
@@ -185,7 +185,8 @@ def test_megakernel_sentinel_boundaries():
 def test_fused_decomposition_matches_single_backend():
     edges = _random_edges(500, 2000, 100, seed=42)
     ref = run_cluster(edges, SingleDeviceBackend(edges), tau=8, seed=1)
-    fused = run_cluster(edges, PallasBackend(edges, impl="ref", fuse=4),
+    fused = run_cluster(edges,
+                        PallasBackend(edges, impl="interpret", fuse=4),
                         tau=8, seed=1)
     np.testing.assert_array_equal(ref.final_c, fused.final_c)
     np.testing.assert_array_equal(ref.final_pathw, fused.final_pathw)
@@ -236,7 +237,7 @@ def test_megakernel_vmem_guard_falls_back_to_unfused(monkeypatch):
     edges = _random_edges(40, 80, 9, seed=0)
     with warnings.catch_warnings(record=True) as rec:
         warnings.simplefilter("always")
-        be = PallasBackend(edges, impl="ref", fuse=4)
+        be = PallasBackend(edges, impl="interpret", fuse=4)
     assert be.fuse == 4 and not rec  # small graph fits; no warning path
     assert fits_vmem(be.n_pad, 256, 512)
     assert not fits_vmem(10**9, 256, 512)
@@ -246,24 +247,32 @@ def test_megakernel_vmem_guard_falls_back_to_unfused(monkeypatch):
     # not a crash mid-decomposition
     monkeypatch.setattr(megakernel, "fits_vmem", lambda *a, **k: False)
     with pytest.warns(RuntimeWarning, match="VMEM budget"):
-        be2 = PallasBackend(edges, impl="ref", fuse=4)
+        be2 = PallasBackend(edges, impl="interpret", fuse=4)
     assert be2.fuse == 0
     with pytest.raises(ValueError, match="fuse"):
-        PallasBackend(edges, impl="ref", fuse=-1)
+        PallasBackend(edges, impl="interpret", fuse=-1)
+
+
+@pytest.mark.parametrize("impl", ["pallas", "ref", "auto"])
+def test_pallas_backend_rejects_fuse_outside_interpret(impl):
+    """The TPU compiler refuses the megakernel's in-kernel 1-D gather, so
+    fuse > 0 is legal only in interpret mode — on every platform."""
+    edges = _random_edges(40, 80, 9, seed=0)
+    with pytest.raises(ValueError, match="1-D gather"):
+        PallasBackend(edges, impl=impl, fuse=4)
 
 
 # ---------------------------------------------------------------------------
-# dispatch fallback (satellite: CPU-honest impl="pallas")
+# dispatch: compiled Pallas off-TPU is an error, never a quiet ref fallback
 # ---------------------------------------------------------------------------
 
 def test_edge_relax_pallas_impl_falls_back_on_cpu():
     import jax
 
-    from repro.kernels.edge_relax import ops
     from repro.kernels.edge_relax.ops import block_edges_host, edge_relax
 
     if jax.default_backend() == "tpu":
-        pytest.skip("fallback only engages off-TPU")
+        pytest.skip("the error only engages off-TPU")
     r = np.random.default_rng(2)
     n, e = 100, 400
     src = r.integers(0, n, e).astype(np.int32)
@@ -282,10 +291,12 @@ def test_edge_relax_pallas_impl_falls_back_on_cpu():
             jnp.asarray(blk["w"]), jnp.asarray(blk["mask"]),
             jnp.asarray(blk["block_tile"]), jnp.int32(19), blk["n_tiles"])
 
-    ops._PALLAS_FALLBACK_WARNED = False
-    with pytest.warns(RuntimeWarning, match="falling back"):
-        pal = edge_relax(*args, impl="pallas")
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        edge_relax(*args, impl="pallas")
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        PallasBackend(EdgeList(n, src, dst, w), impl="pallas")
+    # the interpreted kernel still answers, identical to the oracle
     ref = edge_relax(*args, impl="ref")
+    pal = edge_relax(*args, impl="interpret")
     for r_, p_ in zip(ref, pal):
         np.testing.assert_array_equal(np.asarray(r_), np.asarray(p_))
-    assert ops._PALLAS_FALLBACK_WARNED

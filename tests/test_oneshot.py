@@ -298,15 +298,16 @@ def test_tuning_record_mode_derivation_and_validation():
 
     from repro.core.autotune import (AutotuneError, compute_graph_stats,
                                      derive_tuning, validate_tuning)
+    from repro.runtime.roofline import V5E
 
     g = random_geometric(2000, avg_degree=3.0, seed=1)
     stats = compute_graph_stats(g)
-    rec = derive_tuning(stats)
+    rec = derive_tuning(stats, peaks=V5E)
     assert rec.mode in ("stages", "oneshot")  # never "auto": records store
-    validate_tuning(rec, stats)               # the RESOLVED mode
+    validate_tuning(rec, stats, V5E)          # the RESOLVED mode
     for bad in ("auto", "bogus"):
         with pytest.raises(AutotuneError, match="mode"):
-            validate_tuning(dataclasses.replace(rec, mode=bad), stats)
+            validate_tuning(dataclasses.replace(rec, mode=bad), stats, V5E)
     # cfg.mode="auto" on a tuned session resolves to the record's choice;
     # the default "stages" stays pinned even under autotune
     from repro.config.base import GraphEngineConfig

@@ -254,3 +254,29 @@ def test_pipeline_shards_differ():
     a = p.batch(DataCursor(step=0, shard=0))
     b = p.batch(DataCursor(step=0, shard=1))
     assert not np.array_equal(a["tokens"], b["tokens"])
+
+
+# ---------------------------------------------------------------------------
+# persistent compilation cache
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_dir(env_dir, monkeypatch, tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` is left to JAX; without it the cache
+    goes to the fixed ``<checkout>/.jax_cache``."""
+    from repro.common import checkout_root, enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = os.path.join(checkout_root(), ".jax_cache")
+    else:
+        want = str(tmp_path / env_dir)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+    try:
+        assert enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == (
+            before if env_dir else want)
+        assert os.path.isfile(os.path.join(checkout_root(), "ROADMAP.md"))
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
