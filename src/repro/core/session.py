@@ -170,12 +170,14 @@ class GraphSession:
             self.cfg = dataclasses.replace(self.cfg, mode=mode_resolved)
 
         # the open/pack cost center: backend construction uploads the edge
-        # buffers and (for the Pallas backend) runs the host blocking pass
+        # buffers and (for the Pallas backend) runs the host blocking pass;
+        # a traced open closes once the resident arrays are on the device
         with telemetry.span("session.open", nodes=edges.n_nodes,
                             edges=edges.n_edges, mode=self.cfg.mode) as sp:
             if backend is None:
                 backend = self._build_backend()
             sp.set(backend=getattr(backend, "kind", "custom"))
+            sp.wait(*backend.graph_args())
         # a prebuilt backend counts too: its construction and edge upload
         # are this session's open cost (they happened, just outside) — the
         # warm-query contract must account for them either way
@@ -322,7 +324,11 @@ class GraphSession:
                 estimator = CascadeEstimator(levels=self.tuning.levels)
             else:
                 estimator = ClusterQuotientEstimator()
-        return estimator.estimate(self)
+        # the query's root span: what no layer span holds (estimator glue,
+        # host reductions) is its exclusive time
+        with telemetry.span("session.estimate",
+                            estimator=type(estimator).__name__):
+            return estimator.estimate(self)
 
     # -- dynamic updates ----------------------------------------------------
 

@@ -407,3 +407,230 @@ class TestEqualityContractsUnderTracing:
         assert m_on.transfers == m_off.transfers
         np.testing.assert_array_equal(dec_on.final_c, dec_off.final_c)
         np.testing.assert_array_equal(dec_on.final_pathw, dec_off.final_pathw)
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock, program builds per span, Span.wait
+# ---------------------------------------------------------------------------
+
+
+class _Notes:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs opens and
+    closes."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name, **kw):
+        log = self.log
+
+        class Note:
+            def __enter__(self):
+                log.append(("open", name))
+                return self
+
+            def __exit__(self, *exc):
+                log.append(("close", name))
+
+        return Note()
+
+
+class TestProfilerAnnotations:
+    def test_one_annotation_per_span_under_a_tracer(self, monkeypatch):
+        import jax.profiler
+
+        notes = _Notes()
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", notes)
+        with telemetry.tracing(Tracer()):
+            with telemetry.span("outer", k=1):
+                with telemetry.span("inner"):
+                    pass
+        assert notes.log == [("open", "outer"), ("open", "inner"),
+                             ("close", "inner"), ("close", "outer")]
+
+    def test_no_annotation_without_a_tracer(self, monkeypatch):
+        import jax.profiler
+
+        notes = _Notes()
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", notes)
+        with telemetry.span("a"):
+            with telemetry.span("b"):
+                pass
+        assert notes.log == []
+
+    def test_non_lifo_close_keeps_the_annotation_open(self, monkeypatch):
+        import jax.profiler
+
+        notes = _Notes()
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", notes)
+        with telemetry.tracing(Tracer()):
+            s1, s2 = telemetry.span("a"), telemetry.span("b")
+            s1.__enter__()
+            s2.__enter__()
+            with pytest.raises(RuntimeError):
+                s1.__exit__(None, None, None)
+            assert ("close", "a") not in notes.log
+            s2.__exit__(None, None, None)
+            s1.__exit__(None, None, None)
+        assert notes.log[-2:] == [("close", "b"), ("close", "a")]
+
+
+class TestBuildAttribution:
+    def test_fresh_jit_builds_once_in_the_innermost_span(self):
+        import jax
+        import jax.monitoring
+
+        seen = []
+
+        def count(event, seconds, **kw):
+            if event == telemetry.COMPILE_EVENT:
+                seen.append(seconds)
+
+        x = np.arange(7, dtype=np.int32)
+        t = Tracer()
+        jax.monitoring.register_event_duration_secs_listener(count)
+        try:
+            with telemetry.tracing(t):
+                jax.jit(lambda v: v * 2 + 1)(x).block_until_ready()
+                with telemetry.span("outer"):
+                    with telemetry.span("inner"):
+                        jax.jit(lambda v: v * 5 - 3)(x).block_until_ready()
+        finally:
+            jax.monitoring.unregister_event_duration_listener(count)
+        by_name = {s.name: s for s in t.spans}
+        assert by_name["inner"].builds == 1
+        assert by_name["inner"].build_s > 0
+        assert by_name["outer"].builds == 0
+        assert by_name["outer"].build_s == 0.0
+        assert t.builds_outside >= 1
+        assert t.total_builds() == len(seen)
+        assert t.total_build_s() >= sum(seen)
+
+    def test_no_tracer_attributes_nothing(self):
+        import jax
+
+        t = Tracer()
+        with telemetry.tracing(t):
+            pass
+        # the listener stays registered once installed; with no tracer
+        # installed it returns at once
+        jax.jit(lambda v: v + 11)(np.arange(3)).block_until_ready()
+        assert t.total_builds() == 0 and t.builds_outside == 0
+
+    def test_events_of_other_kinds_are_ignored(self):
+        t = Tracer()
+        with telemetry.tracing(t):
+            with telemetry.span("s"):
+                telemetry._on_build_event("/jax/some/other_duration", 3.0)
+        assert t.spans[0].builds == 0 and t.spans[0].build_s == 0.0
+
+
+class TestSpanWait:
+    def test_wait_blocks_on_the_arrays(self, monkeypatch):
+        import jax
+        import jax.numpy as jnp
+
+        waited = []
+        real = jax.block_until_ready
+        monkeypatch.setattr(jax, "block_until_ready",
+                            lambda x: waited.append(x) or real(x))
+        a, b = jnp.arange(4), jnp.ones(3)
+        t = Tracer()
+        with telemetry.tracing(t), guard.metered() as m:
+            with telemetry.span("s") as sp:
+                assert sp.wait(a, b) is sp
+        assert len(waited) == 1
+        assert waited[0][0] is a and waited[0][1] is b
+        assert m.transfers == 0 and t.spans[0].transfers == 0
+
+    def test_null_span_wait_is_a_no_op(self, monkeypatch):
+        import jax
+
+        def refuse(x):
+            raise AssertionError("NULL_SPAN.wait blocked")
+
+        monkeypatch.setattr(jax, "block_until_ready", refuse)
+        with telemetry.span("s") as sp:
+            assert sp is telemetry.NULL_SPAN
+            assert sp.wait(np.arange(2)) is sp
+
+
+class TestBuildFieldsExported:
+    def _tracer(self):
+        t = Tracer()
+        with telemetry.tracing(t):
+            with telemetry.span("outer"):
+                with telemetry.span("inner"):
+                    telemetry._on_build_event(telemetry.COMPILE_EVENT, 0.25)
+                    telemetry._on_build_event(
+                        "/jax/core/compile/jaxpr_trace_duration", 0.5)
+        return t
+
+    def test_chrome_trace_carries_builds(self, tmp_path):
+        path = tmp_path / "trace.json"
+        export_chrome_trace(self._tracer(), str(path))
+        events = {e["name"]: e for e in
+                  json.loads(path.read_text())["traceEvents"]}
+        assert events["inner"]["args"]["builds"] == 1
+        assert events["inner"]["args"]["build_s"] == pytest.approx(0.75)
+        assert events["outer"]["args"]["builds"] == 0
+        assert events["outer"]["args"]["build_s"] == 0.0
+
+    def test_jsonl_carries_builds(self, tmp_path):
+        path = tmp_path / "spans.jsonl"
+        export_jsonl(self._tracer(), None, str(path))
+        rows = {r["name"]: r for r in
+                map(json.loads, path.read_text().splitlines())}
+        assert rows["inner"]["builds"] == 1
+        assert rows["inner"]["build_s"] == pytest.approx(0.75)
+        assert rows["outer"]["builds"] == 0
+
+
+class TestSessionSpans:
+    def test_estimate_span_wraps_the_query(self):
+        from repro.core import IntervalEstimator, open_session
+
+        t = Tracer()
+        with open_session(_graph(), tau=12) as sess:
+            with telemetry.tracing(t), guard.measured_transfers() as meter:
+                res = sess.estimate(IntervalEstimator())
+        roots = [s for s in t.spans if s.parent is None]
+        assert [s.name for s in roots] == ["session.estimate"]
+        root = roots[0]
+        assert root.attrs["estimator"] == "IntervalEstimator"
+        assert root.transfers_incl == meter.transfers
+        assert t.total_transfers() == meter.transfers
+        assert meter.transfers == res.pipeline.total_host_syncs
+        assert all(s.start >= root.start and
+                   s.start + s.duration <= root.start + root.duration + 1e-9
+                   for s in t.spans)
+
+    def test_default_estimator_is_named(self):
+        from repro.core import open_session
+
+        t = Tracer()
+        with open_session(_graph(), tau=12) as sess:
+            with telemetry.tracing(t):
+                sess.estimate()
+        root = [s for s in t.spans if s.name == "session.estimate"]
+        assert [s.attrs["estimator"] for s in root] == [
+            "ClusterQuotientEstimator"]
+
+    def test_traced_open_waits_for_the_resident_arrays(self, monkeypatch):
+        from repro.core import open_session
+
+        waited = []
+        real = telemetry.Span.wait
+
+        def wait(self, *arrays):
+            waited.append((self.name, arrays))
+            return real(self, *arrays)
+
+        monkeypatch.setattr(telemetry.Span, "wait", wait)
+        t = Tracer()
+        with telemetry.tracing(t), guard.metered() as m:
+            sess = open_session(_graph(), tau=12)
+        assert [n for n, _ in waited] == ["session.open"]
+        assert len(waited[0][1]) == len(sess.backend.graph_args())
+        assert m.transfers == 0
+        sess.close()
