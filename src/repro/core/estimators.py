@@ -541,32 +541,41 @@ def _trivial_estimate(method: str, n_nodes: int) -> DiameterEstimate:
 
 def _sssp_from(session: GraphSession, source: int, delta: Optional[int]):
     """One SSSP on the resident edge arrays; ONE packed host fetch of
-    (dist, supersteps). ``delta=None`` -> Bellman-Ford. Returns
-    (dist, supersteps, inf) — the distance dtype follows the same provable
-    bound as ``sssp.bellman_ford`` (int64 when ``n * max_weight`` would
-    overflow int32, so heavy-weight graphs never wrap negative)."""
+    (dist, supersteps, widened). ``delta=None`` -> Bellman-Ford, narrow
+    first (``sssp.bf_from``: int32 with a saturating add, rerun in int64
+    on the device only if it saturated). Returns (dist, supersteps, inf) —
+    ``inf`` follows the same provable bound as ``sssp.bellman_ford``
+    (INF64 when ``n * max_weight`` would overflow int32, so heavy-weight
+    graphs never wrap negative). The ``sssp.solve`` span carries the dtype
+    the distances were computed in and ``widened`` (0/1), which
+    ``SessionMetrics.sssp_widened`` counts."""
     import jax
     import jax.numpy as jnp
 
-    from repro.core.sssp import _bf_loop, _delta_stepping_loop, sssp_dtype_for
+    from repro.core.sssp import _delta_stepping_loop, bf_from, sssp_dtype_for
 
     n = session.n_nodes
     src, dst, w = session.flat_device_edges()
-    # dtype: delta=None means unbucketed; None and 0 pick the same bound
-    dtype, inf = sssp_dtype_for(n, session.max_weight, delta or 0)
     with jax.enable_x64(True), telemetry.span("sssp.solve", source=source) as sp:
-        infj = jnp.asarray(inf, dtype)
-        d0 = jnp.full(n, infj, dtype=dtype).at[source].set(0)
-        wd = w.astype(dtype)
         if delta is None:
-            d, k = _bf_loop(src, dst, wd, d0, infj, n)
+            d, k, widened, inf = bf_from(src, dst, w, source, n,
+                                         session.max_weight)
+            dtype = jnp.int32
         else:
-            d, k = _delta_stepping_loop(src, dst, wd, d0,
+            dtype, inf = sssp_dtype_for(n, session.max_weight, delta)
+            infj = jnp.asarray(inf, dtype)
+            d0 = jnp.full(n, infj, dtype=dtype).at[source].set(0)
+            d, k = _delta_stepping_loop(src, dst, w.astype(dtype), d0,
                                         jnp.asarray(delta, dtype), infj, n)
+            widened = False
         out = guard.fetch(jnp.concatenate(
-            [d.astype(jnp.int64), k[None].astype(jnp.int64)]),
-            reason="sssp estimator: packed (dist plane, supersteps)")
-        sp.set(supersteps=int(out[n]))
+            [d.astype(jnp.int64), jnp.asarray(k, jnp.int64)[None],
+             jnp.asarray(widened, jnp.int64)[None]]),
+            reason="sssp estimator: packed (dist plane, supersteps, widened)")
+        widened = int(out[n + 1])
+        sp.set(supersteps=int(out[n]), widened=widened,
+               dtype="int64" if widened else np.dtype(dtype).name)
+    session.metrics.sssp_widened += widened
     return out[:n], int(out[n]), inf
 
 

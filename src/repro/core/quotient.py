@@ -41,9 +41,15 @@ from repro.graph.structures import MAX_WEIGHT, EdgeList, weight_scale_for
 # Unreached sentinel for the int64 solve. Guarded adds keep everything
 # strictly below 2 * INF64 < 2^63, so int64 arithmetic never overflows.
 INF64 = np.int64(2**62)
-# k is padded to a multiple of this (and m to a multiple of 8x) so the solve
-# program re-compiles only per size bucket, not per graph.
+# the dynamic merge pads the cached quotient's edge count to a multiple of
+# 8x this, so its program re-compiles only per size bucket
 K_BUCKET = 16
+# the solve pads k and m up to one of this many sizes per power of two: the
+# cluster and quotient-edge counts follow each query's random centers, and
+# every (k_pad, m_pad) is a program the device holds (about 0.6 MB of HBM
+# each on a v5e), so the buckets must be coarse enough for seeds to share
+# them
+SOLVE_BUCKETS_PER_OCTAVE = 4
 # cascade levels pad the quotient edge arrays to a multiple of this so the
 # per-level engine programs recompile only per size bucket
 LEVEL_EDGE_BUCKET = 256
@@ -409,17 +415,31 @@ def quotient_update_device(
 # ---------------------------------------------------------------------------
 
 
-@partial(jax.jit, static_argnames=("k_pad",))
-def _solve_kernel(qsrc, qdst, qw, k, *, k_pad: int):
+def solve_bucket(x: int) -> int:
+    """``x`` rounded up to one of ``SOLVE_BUCKETS_PER_OCTAVE`` sizes per
+    power of two: at most 1 / SOLVE_BUCKETS_PER_OCTAVE of padding."""
+    step = max(1, (1 << (max(x, 1).bit_length() - 1))
+               // SOLVE_BUCKETS_PER_OCTAVE)
+    return next_multiple(max(x, 1), step)
+
+
+@partial(jax.jit, static_argnames=("k_pad", "m_pad", "int32"))
+def _solve_kernel(qsrc, qdst, qw, k, *, k_pad: int, m_pad: int, int32: bool):
     """Exact APSP on the quotient via Bellman-Ford from ALL k_pad sources at
-    once (``sssp.batched_bf_loop``, distances laid out [node, source]).
-    Distance dtype follows ``qw`` — int32 fast path when the caller proved
-    every shortest path fits, int64 otherwise. Edges are directed (callers
-    pass both directions of the symmetrized graph). Returns one packed
-    int64 vector: [diameter, connected, supersteps, ecc[0..k_pad)].
+    once (``sssp.batched_bf_loop``, distances laid out [node, source]) over
+    the first ``m_pad`` edge slots, sliced here so that one program serves
+    a (k_pad, m_pad) bucket. ``int32``: the caller proved every shortest
+    path fits, so the loop runs in int32 (padding slots' INF64 weights map
+    to the int32 INF); int64 otherwise. Edges are directed (callers pass
+    both directions of the symmetrized graph). Returns one packed int64
+    vector: [diameter, connected, supersteps, ecc[0..k_pad)].
     """
     from repro.core.sssp import batched_bf_loop
 
+    qsrc, qdst, qw = qsrc[:m_pad], qdst[:m_pad], qw[:m_pad]
+    if int32:
+        qw = jnp.where(qw >= jnp.int64(INF64),
+                       jnp.int64(2**31 - 1), qw).astype(jnp.int32)
     inf = jnp.asarray(
         2**62 if qw.dtype == jnp.int64 else 2**31 - 1, qw.dtype)
     s = jnp.clip(qsrc, 0, k_pad - 1).astype(jnp.int32)
@@ -444,8 +464,9 @@ def solve_device_quotient(
 ) -> Tuple[int, np.ndarray, bool, int]:
     """(diameter, eccentricities, connected, supersteps) from a device
     quotient whose (n_clusters, n_edges, max_weight) counters have been
-    fetched. Pads k and m to size buckets so same-scale graphs share one
-    compiled solve, then fetches the packed result — ONE host sync.
+    fetched. Pads k and m to size buckets (``solve_bucket``) so queries and
+    same-scale graphs share one compiled solve, then fetches the packed
+    result — ONE host sync.
 
     When ``k_pad * max_weight < 2^31 - 1`` the solve runs in int32 (every
     shortest path has < k edges, so distances and guarded adds provably
@@ -454,19 +475,13 @@ def solve_device_quotient(
     """
     if k <= 1:
         return 0, np.zeros(k, np.int64), True, 0
-    k_pad = next_multiple(k, K_BUCKET)
-    E = dq.src.shape[0]
-    m_pad = min(next_multiple(max(m, 1), 8 * K_BUCKET), E)
+    k_pad = solve_bucket(k)
+    m_pad = min(solve_bucket(m), dq.src.shape[0])
     int32_safe = k_pad * max(int(max_weight), 1) < 2**31 - 1
     with jax.enable_x64(True):
-        qw = dq.weight[:m_pad]
-        if int32_safe:
-            # invalid (padding) slots carry INF64 -> map onto the int32 INF
-            qw = jnp.where(qw >= jnp.int64(INF64),
-                           jnp.int64(2**31 - 1), qw).astype(jnp.int32)
         out = guard.fetch(_solve_kernel(
-            dq.src[:m_pad], dq.dst[:m_pad], qw,
-            jnp.int32(k), k_pad=k_pad),
+            dq.src, dq.dst, dq.weight, jnp.int32(k),
+            k_pad=k_pad, m_pad=m_pad, int32=int32_safe),
             reason="quotient solve: packed (diam, connected, steps, ecc)")
     return int(out[0]), out[3:3 + k], bool(out[1]), int(out[2])
 

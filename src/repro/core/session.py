@@ -86,6 +86,7 @@ class SessionMetrics:
     edge_uploads: int = 0     # host->device edge-array placements
     queries: int = 0          # estimator runs against a session
     warm_queries: int = 0     # queries that triggered no build and no upload
+    sssp_widened: int = 0     # narrow-first SSSPs whose int32 run saturated
 
 
 class GraphSession:

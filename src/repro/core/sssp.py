@@ -15,11 +15,23 @@
 
 Distance dtype is picked from a provable bound (``sssp_dtype_for``): every
 shortest path has < n edges, so when ``n * max_weight`` fits int32 the
-loops run in int32; otherwise they run in int64 under ``jax.enable_x64``
+loops run in int32; otherwise they need int64 under ``jax.enable_x64``
 (legal edge weights go up to 2^30 - 1, which overflows int32 after a
 handful of hops — the old int32-only loops silently wrapped negative and
 reported false minima). ``SSSPResult.inf`` carries the unreached sentinel
 of the chosen dtype so callers mask with the right value.
+
+Bellman-Ford runs narrow first (``bf_from``): the bound is a worst case,
+and the distances a graph realizes usually sit far below it. Where the
+bound asks for int64, the loop first runs in int32 with a saturating add
+(a candidate is ``min(d + w, 2^31 - 2)``, computed so that it never
+wraps), whose fixpoint is ``min(true distance, 2^31 - 2)`` on every
+reached node. A second device program then looks for the saturated value:
+if none is there the int32 plane is exact and is widened to int64; if any
+is, the loop reruns in int64 from the same source. Both steps stay on the
+device, with no host sync between them, and the answer is the same int64
+plane either way. Δ-stepping keeps the provable pick (its bucket bound
+would need its own saturation argument).
 
 Disconnected inputs: every estimator surfaces a ``connected`` flag
 (consistent with ``DiameterEstimate.connected``) instead of silently
@@ -65,6 +77,7 @@ class SSSPResult:
     dist: np.ndarray
     supersteps: int
     inf: int = int(2**31 - 1)  # unreached sentinel of dist's dtype
+    widened: bool = False      # the int32 run saturated; int64 rerun
 
 
 @dataclass
@@ -77,10 +90,19 @@ class MultiSSSPResult:
 @partial(jax.jit, static_argnames=("n_nodes",))
 def _bf_loop(src, dst, w, d0, inf, n_nodes: int):
     """Dtype-generic frontier Bellman-Ford; ``inf`` is the unreached
-    sentinel in d0's dtype. Overflow safety comes from the caller's dtype
-    pick (``sssp_dtype_for``): admitted ``ds < inf`` are real path sums
-    < n * max_weight, so the guarded add ``ds + w`` provably fits — int64
-    additionally keeps ``inf`` below dtype_max / 2."""
+    sentinel in d0's dtype (``w`` may be narrower: int32 weights widen
+    inside the add, so an int64 run makes no int64 weight copy).
+
+    The add saturates at ``top = inf - 1``: a candidate is
+    ``min(ds, top - w) + w == min(ds + w, top)``, which never wraps, so the
+    fixpoint is ``min(true distance, top)`` on every reached node and
+    unreached nodes stay ``inf``. Under the provable pick
+    (``sssp_dtype_for``) every candidate is a walk sum of at most n edges,
+    <= n * max_weight <= top, so the saturation never bites and the result
+    is the exact one; ``bf_from`` runs int32 past that bound and reads a
+    ``top`` in the result as "widen"."""
+    top = inf - 1
+
     def cond(carry):
         _, changed, _ = carry
         return changed
@@ -88,8 +110,7 @@ def _bf_loop(src, dst, w, d0, inf, n_nodes: int):
     def body(carry):
         d, _, k = carry
         ds = d[src]
-        ok = ds < inf
-        cand = jnp.where(ok, jnp.where(ok, ds, 0) + w, inf)
+        cand = jnp.where(ds < inf, jnp.minimum(ds, top - w) + w, inf)
         dmin = jax.ops.segment_min(cand, dst, num_segments=n_nodes)
         upd = dmin < d
         return jnp.where(upd, dmin, d), jnp.any(upd), k + 1
@@ -103,18 +124,65 @@ def _edge_arrays(edges: EdgeList, dtype):
             jnp.asarray(edges.weight).astype(dtype))
 
 
+@partial(jax.jit, static_argnames=("n_nodes",))
+def _widen_on_saturation(src, dst, w, d32, k32, source, n_nodes: int):
+    """(int64 dist, supersteps, widened) from a saturating int32 run.
+
+    ``widened`` is true where any node holds the int32 ``top`` (a real
+    distance exactly at ``top`` counts too: a conservative rerun); the
+    int64 loop then reruns from ``source``. Otherwise the int32 plane is
+    exact and only maps its sentinel to ``INF64``. The caller's int32 run
+    and this program follow each other on the device, with no host sync."""
+    widened = jnp.any(d32 == INF - 1)
+
+    def rerun():
+        inf = jnp.asarray(INF64, jnp.int64)
+        d0 = jnp.full(n_nodes, inf).at[source].set(0)
+        d, k = _bf_loop(src, dst, w, d0, inf, n_nodes)
+        return d, k
+
+    def widen():
+        return jnp.where(d32 < INF, d32.astype(jnp.int64), INF64), k32
+
+    d, k = jax.lax.cond(widened, rerun, widen)
+    return d, k, widened
+
+
+def bf_from(src, dst, w, source: int, n_nodes: int, max_weight: int):
+    """Bellman-Ford from ``source`` on device edge arrays with int32
+    weights, narrow first. Call under ``jax.enable_x64(True)``.
+
+    Returns device ``(dist, supersteps, widened)`` and the host ``inf``
+    of dist's dtype. Where ``sssp_dtype_for`` proves int32 enough, the
+    int32 run is the answer (``widened`` is a constant False). Otherwise
+    the same int32 run saturates instead of wrapping, and
+    ``_widen_on_saturation`` turns it into the exact int64 plane, rerunning
+    in int64 only when the run saturated. ``_bf_loop`` is looked up here at
+    call time, outside any enclosing jit."""
+    dtype, inf = sssp_dtype_for(n_nodes, max_weight)
+    d0 = jnp.full(n_nodes, INF, jnp.int32).at[source].set(0)
+    d, k = _bf_loop(src, dst, w, d0, INF, n_nodes)
+    if dtype == jnp.int32:
+        return d, k, np.bool_(False), inf
+    d, k, widened = _widen_on_saturation(src, dst, w, d, k,
+                                         np.int32(source), n_nodes)
+    return d, k, widened, inf
+
+
 def bellman_ford(edges: EdgeList, source: int) -> SSSPResult:
 
     n = edges.n_nodes
     wmax = int(edges.weight.max()) if edges.n_edges else 1
-    dtype, inf = sssp_dtype_for(n, wmax)
     with jax.enable_x64(True):
-        infj = jnp.asarray(inf, dtype)
-        d0 = jnp.full(n, infj, dtype=dtype).at[source].set(0)
-        d, k = _bf_loop(*_edge_arrays(edges, dtype), d0, infj, n)
+        d, k, widened, inf = bf_from(
+            jnp.asarray(edges.src), jnp.asarray(edges.dst),
+            jnp.asarray(edges.weight), source, n, wmax)
         dist = guard.fetch(d, reason="sssp baseline: distance plane")
-        k = int(guard.fetch(k, reason="sssp baseline: superstep counter"))
-    return SSSPResult(dist=dist, supersteps=k, inf=inf)
+        k, widened = (int(x) for x in guard.fetch(
+            jnp.stack([jnp.asarray(k, jnp.int32),
+                       jnp.asarray(widened, jnp.int32)]),
+            reason="sssp baseline: (superstep counter, widened)"))
+    return SSSPResult(dist=dist, supersteps=k, inf=inf, widened=bool(widened))
 
 
 @partial(jax.jit, static_argnames=("n_nodes",))

@@ -92,3 +92,26 @@ def test_quotient_solve_loop_compiles_under_x64(one_chip, dtype):
             n_nodes=K_PAD,
         ).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 12 * 2**30
+
+
+# bench cell kron-g500: its largest component at scale 16
+KRON_N, KRON_ARCS = 46_668, 1_819_286
+
+
+def test_narrow_first_bellman_ford_compiles(one_chip):
+    """The two programs of ``sssp.bf_from`` at the Kronecker cell's size:
+    the int32 superstep keeps no arc-length temporary, and the widening
+    program's int64 rerun adds int32 weights inside the add, so it holds
+    no int64 weight copy besides its int64 gather."""
+    from repro.core.sssp import _bf_loop, _widen_on_saturation
+
+    arcs = _spec(one_chip, (KRON_ARCS,))
+    plane = _spec(one_chip, (KRON_N,))
+    scalar = _spec(one_chip, ())
+    with jax.enable_x64(True):
+        loop = _bf_loop.lower(arcs, arcs, arcs, plane, scalar,
+                              n_nodes=KRON_N).compile()
+        widen = _widen_on_saturation.lower(arcs, arcs, arcs, plane, scalar,
+                                           scalar, n_nodes=KRON_N).compile()
+    assert loop.memory_analysis().temp_size_in_bytes < KRON_ARCS * 4
+    assert widen.memory_analysis().temp_size_in_bytes < 2 * KRON_ARCS * 8

@@ -162,6 +162,57 @@ def test_device_solve_exact_int64_up_to_2_40():
     assert len(ecc) == q.n_clusters
 
 
+@pytest.mark.parametrize("x", [1, 2, 3, 7, 16, 17, 240, 257, 296, 1490,
+                               3250, 5208, 2**20 + 1])
+def test_solve_bucket_pads_at_most_a_quarter(x):
+    """The solve's size buckets: at least ``x``, at most 25 % above it, and
+    a bucket is its own bucket."""
+    from repro.core.quotient import solve_bucket
+
+    b = solve_bucket(x)
+    assert x <= b <= 1.25 * x
+    assert solve_bucket(b) == b
+
+
+def test_device_solve_shares_one_program_per_bucket():
+    """Quotients whose k and m fall in one bucket reuse one compiled solve,
+    and their answers stay exact (padding slots never relax)."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.quotient import (INF64, DeviceQuotient, _solve_kernel,
+                                     solve_device_quotient)
+
+    def solve(q, cap=2048):
+        src = np.concatenate([q.src, q.dst]).astype(np.int32)
+        dst = np.concatenate([q.dst, q.src]).astype(np.int32)
+        w = np.concatenate([q.weight, q.weight]).astype(np.int64)
+        pad = cap - len(src)
+        with jax.enable_x64(True):
+            dq = DeviceQuotient(
+                centers=jnp.arange(q.n_clusters, dtype=jnp.int32),
+                src=jnp.asarray(np.pad(src, (0, pad))),
+                dst=jnp.asarray(np.pad(dst, (0, pad))),
+                weight=jnp.asarray(np.pad(w, (0, pad),
+                                          constant_values=INF64)),
+                n_clusters=jnp.int32(q.n_clusters),
+                n_edges=jnp.int32(len(src)), max_weight=jnp.int64(w.max()),
+                weight_sum=jnp.int64(w.sum()))
+        diam, _, connected, _ = solve_device_quotient(
+            dq, q.n_clusters, len(src), int(w.max()))
+        return diam, connected
+
+    # k 140..160 and m 1120..1280 share the bucket (160, 1280); a fixed
+    # granularity of 16 and 128 would split them over two programs
+    qs = [_synthetic_quotient(k, 4 * k, 1, 2**30, seed=k)
+          for k in (140, 150, 160)]
+    assert solve(qs[0]) == quotient_diameter(qs[0])
+    before = _solve_kernel._cache_size()
+    for q in qs[1:]:
+        assert solve(q) == quotient_diameter(q)
+    assert _solve_kernel._cache_size() == before
+
+
 def test_quotient_solvers_agree_on_disconnected():
     """Regression: the fallback used to return a bare finite max on a
     disconnected quotient while scipy returned (diameter, connected). All
